@@ -156,13 +156,19 @@ class Engine:
         # the duration of each dispatch (a ShotRunner may be shared by
         # engines of different backends — never mutate it permanently)
         from repro.core.executor import execute
-        self._value_fn = _pallas_value_fn if backend == "pallas" else execute
+        self._value_fn = execute
+        if backend == "pallas":
+            from repro.kernels.fabric_reduce import use_compile_cache
+            use_compile_cache()
+            self._value_fn = _pallas_value_fn
         self.backend = backend
         self.cache = cache if cache is not None else default_cache()
         # None = resolve per compile from STRELA_MAPPER (so one Engine can
         # follow the env); a concrete value pins every compile it issues
         self.mapper = mapper
         self.stats = EngineStats()
+        # text of the last lane-grid exception flush() fell back from
+        self.last_lane_error: Optional[str] = None
         self._queue: List[Handle] = []
         self._flushing = False
 
@@ -309,14 +315,16 @@ class Engine:
                         current = batch
                         try:
                             outs_list = self._run_lanes(batch)
-                        except Exception:
+                        except Exception as e:
                             # the grid fails as a unit with no way to tell
                             # which lane is at fault: fall back to
                             # per-request dispatch so only the actually-bad
-                            # request is affected — counted, so a systematic
-                            # grid regression (batching silently lost) is
-                            # observable in the stats
+                            # request is affected — counted (and the cause
+                            # kept), so a systematic grid regression
+                            # (batching silently lost) is observable
                             self.stats.lane_batch_failures += 1
+                            self.last_lane_error = \
+                                f"{type(e).__name__}: {e}"
                             obs.inc("engine.lane_batch_failures")
                             outs_list = None
                     if outs_list is not None:

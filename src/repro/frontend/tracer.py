@@ -448,12 +448,10 @@ def _h_broadcast(lw: Lowerer, eqn, ins: List[Value]) -> List[Value]:
 
 
 def _h_inline_call(param_key: str):
-    """Inline pjit/closed_call/custom_jvp-style sub-jaxprs."""
+    """Inline jit/closed_call/custom_jvp_call sub-jaxprs (each carries a
+    ClosedJaxpr under ``param_key``)."""
     def h(lw: Lowerer, eqn, ins: List[Value]) -> List[Value]:
         closed = eqn.params[param_key]
-        if param_key == "call_jaxpr" and not hasattr(closed, "jaxpr"):
-            # custom_jvp_call in some versions stores an open jaxpr
-            return lw.lower_jaxpr(closed, (), ins)
         n_ins = len(ins)
         if eqn.primitive.name == "custom_jvp_call":
             # trailing invars may be jvp residuals; sub-jaxpr decides
@@ -489,7 +487,7 @@ _HANDLERS: Dict[str, Callable] = {
     "copy": _h_alias,
     "broadcast_in_dim": _h_broadcast,
     "reshape": _h_alias,
-    "pjit": _h_inline_call("jaxpr"),
+    "jit": _h_inline_call("jaxpr"),
     "closed_call": _h_inline_call("call_jaxpr"),
     "custom_jvp_call": _h_inline_call("call_jaxpr"),
 }

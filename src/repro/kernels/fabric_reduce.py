@@ -8,26 +8,31 @@ to the two kernel classes that previously fell back to the simulator:
   * **accumulator reductions** (running-sum trees from the frontend's
     ``patterns.py``, mac1/mac3/mac2x dot products): the DFG's elementwise
     prologue evaluates on (block_rows, 128) VMEM tiles exactly as in
-    ``fabric_stream``; each reduction node then tile-reduces its operand
-    (associative ops only — the capability matrix keeps SHL/SHR
-    accumulators on the sequential simulator) and folds the partial into a
-    **carry block** that persists across sequential grid steps — the TPU
-    image of the PE's immediate-feedback accumulator register. Padding
-    lanes are masked to the op's identity element, and the single emission
-    (``emit_every`` 0 or the stream length) lands in a (1, 1) output block.
+    ``fabric_stream``; each reduction node then folds its masked operand
+    tile *elementwise* into a tile-shaped **carry block** that persists
+    across sequential grid steps — the TPU image of the PE's
+    immediate-feedback accumulator register (associative ops only — the
+    capability matrix keeps SHL/SHR accumulators on the sequential
+    simulator). Padding lanes are masked to the op's identity element. The
+    last tile -> scalar fold and the node's ``acc_init`` run after the
+    kernel, in XLA on the same device: Mosaic lowers elementwise int32 ops
+    on tiles, but neither a product or bitwise reduction to a scalar nor a
+    scalar store to VMEM.
 
   * **lane batching** (mirroring PR 4's ``simulate_lanes``): N same-mapping
     requests stack lane-major into one padded grid — lane k owns grid steps
-    [k*bpl, (k+1)*bpl) — and carries reset at lane boundaries, so one
-    ``pallas_call`` serves a whole config-class batch from
-    ``Engine.submit``/``flush``.
+    [k*bpl, (k+1)*bpl) and its own carry blocks, which reset at the lane's
+    first step — so one ``pallas_call`` serves a whole config-class batch
+    from ``Engine.submit``/``flush``.
 
-Everything runs under ``interpret=True`` on CPU (the hermetic CI
-configuration); on a TPU the same lowering compiles via Mosaic.
+Off the accelerator the kernels run under ``interpret=True``
+(:func:`default_interpret`); on a TPU they compile via Mosaic.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import os
+from pathlib import Path
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +52,16 @@ I32 = np.int32
 _IDENTITY = {AluOp.ADD: 0, AluOp.SUB: 0, AluOp.XOR: 0, AluOp.OR: 0,
              AluOp.AND: -1, AluOp.MUL: 1}
 
+# elementwise fold of a reduction's operands (SUB accumulates
+# acc - x0 - x1 - ... = acc - sum(x), so its operands fold with ADD)
+_FOLD = {AluOp.ADD: jnp.add, AluOp.SUB: jnp.add, AluOp.MUL: jnp.multiply,
+         AluOp.AND: jnp.bitwise_and, AluOp.OR: jnp.bitwise_or,
+         AluOp.XOR: jnp.bitwise_xor}
+
+# fixed, git-ignored home of JAX's persistent compilation cache inside the
+# checkout (a stable path: the directory is part of the cache key)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
 
 def default_interpret() -> bool:
     """The kernels' interpret-mode policy (single source of truth — the
@@ -55,37 +70,41 @@ def default_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _tile_reduce(op: AluOp, x: jax.Array) -> jax.Array:
-    """Reduce one masked tile to a scalar partial (int32, wrapping)."""
-    if op in (AluOp.ADD, AluOp.SUB):
-        return jnp.sum(x, dtype=jnp.int32)
-    if op == AluOp.MUL:
-        return jnp.prod(x, dtype=jnp.int32)
-    fn = {AluOp.AND: jnp.bitwise_and, AluOp.OR: jnp.bitwise_or,
-          AluOp.XOR: jnp.bitwise_xor}[op]
-    return jax.lax.reduce(x, jnp.int32(_IDENTITY[op]),
-                          lambda a, b: fn(a, b), tuple(range(x.ndim)))
+def use_compile_cache() -> Optional[str]:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads that variable itself, so
+    nothing is set then), else at :data:`COMPILE_CACHE_DIR`. In interpret
+    mode the cache stays off. Call before the process's first compile.
+    Returns the directory in use, or None."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if default_interpret():
+        return None
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir",
+                          str(COMPILE_CACHE_DIR))
+    return jax.config.jax_compilation_cache_dir
 
 
-def _combine(op: AluOp, carry: jax.Array, part: jax.Array) -> jax.Array:
-    """Fold a tile partial into the running carry (associativity lets the
-    tile order stand in for the element order)."""
-    if op == AluOp.ADD:
-        return carry + part
-    if op == AluOp.SUB:
-        return carry - part        # acc - x0 - x1 - ... = acc - sum(x)
-    if op == AluOp.MUL:
-        return carry * part
-    fn = {AluOp.AND: jnp.bitwise_and, AluOp.OR: jnp.bitwise_or,
-          AluOp.XOR: jnp.bitwise_xor}[op]
-    return fn(carry, part)
+def _output_roles(g: D.DFG) -> Tuple[Dict[str, str], List[str], List[str]]:
+    """Classify outputs: reduction-fed (output -> its reduction node, one
+    carry block per node) vs full-rate streams (tile blocks)."""
+    red_of: Dict[str, str] = {}
+    for o in g.outputs:
+        e = g.operand(o, "a")
+        if g.nodes[e.src].is_reduction():
+            red_of[o] = e.src
+    full_names = [o for o in g.outputs if o not in red_of]
+    red_names = sorted(set(red_of.values()))
+    return red_of, full_names, red_names
 
 
-def _emit_body(g: D.DFG, in_names: List[str], full_names: List[str],
-               red_names: List[str], bpl: int, length: int,
-               block_rows: int):
-    """Kernel body: elementwise prologue on the tile, reduction carries
-    across grid steps, carry reset at lane boundaries."""
+def _emit_body(g: D.DFG, full_names: List[str], red_names: List[str],
+               bpl: int, length: int, block_rows: int):
+    """Kernel body: elementwise prologue on the tile, elementwise carry
+    folds across grid steps, carry reset at lane boundaries."""
+    in_names = list(g.inputs)
 
     def body(*refs):
         ins = refs[:len(in_names)]
@@ -104,17 +123,73 @@ def _emit_body(g: D.DFG, in_names: List[str], full_names: List[str],
         idx = j * (block_rows * LANES) + row * LANES + col
         valid = idx < length               # mask the per-lane padding tail
         for rname, rref in zip(red_names, red_refs):
-            node = g.nodes[rname]
-            x = jnp.where(valid, red_ins[rname], _IDENTITY[node.op])
-            part = _tile_reduce(node.op, x)
+            op = g.nodes[rname].op
+            x = jnp.where(valid, red_ins[rname], _IDENTITY[op])
 
             @pl.when(j == 0)
-            def _(rref=rref, node=node):   # new lane: reset the carry
-                rref[0, 0] = jnp.int32(node.acc_init)
+            def _(rref=rref, op=op):       # new lane: reset the carry
+                rref[...] = jnp.full(rref.shape, _IDENTITY[op], jnp.int32)
 
-            rref[0, 0] = _combine(node.op, rref[0, 0], part)
+            rref[...] = _FOLD[op](rref[...], x)
 
     return body
+
+
+def _finish(node: D.Node, carry: jax.Array) -> jax.Array:
+    """Fold each lane's ``(n_lanes, tile)`` carry row to a scalar and apply
+    the accumulator's initial value (wrapping int32: associativity and
+    commutativity let the tile order stand in for the element order)."""
+    op = node.op
+    part = jax.lax.reduce(carry, jnp.int32(_IDENTITY[op]), _FOLD[op], (1,))
+    acc = jnp.int32(node.acc_init)
+    return acc - part if op == AluOp.SUB else _FOLD[op](acc, part)
+
+
+def lanes_call(g: D.DFG, n_lanes: int, length: int, block_rows: int = 8,
+               interpret: Optional[bool] = None) -> Callable:
+    """Build the lane-batched grid for ``n_lanes`` requests of ``length``
+    elements.
+
+    The returned function takes one ``(n_lanes * padded // 128, 128)``
+    int32 array per ``g.inputs`` name — each lane's stream zero-padded to
+    ``padded`` (whole tiles), lanes stacked in order — and returns the
+    full-rate output streams in the same layout, then one ``(n_lanes,)``
+    array per reduction node (:func:`_output_roles` order). It needs
+    shapes only, so it lowers for a described chip as well."""
+    if interpret is None:
+        interpret = default_interpret()
+    _, full_names, red_names = _output_roles(g)
+    tile = block_rows * LANES
+    padded = pl.cdiv(length, tile) * tile
+    bpl = padded // tile                   # tiles (grid steps) per lane
+    block = (block_rows, LANES)
+    in_specs = [pl.BlockSpec(block, lambda i: (i, 0)) for _ in g.inputs]
+    out_specs = [pl.BlockSpec(block, lambda i: (i, 0)) for _ in full_names]
+    # one carry block per reduction node and lane, revisited by every grid
+    # step of that lane (sequential TPU grids make the accumulation sound)
+    out_specs += [pl.BlockSpec(block, lambda i: (i // bpl, 0))
+                  for _ in red_names]
+    out_shapes = [jax.ShapeDtypeStruct((n_lanes * padded // LANES, LANES),
+                                       jnp.int32) for _ in full_names]
+    out_shapes += [jax.ShapeDtypeStruct((n_lanes * block_rows, LANES),
+                                        jnp.int32) for _ in red_names]
+    call = pl.pallas_call(
+        _emit_body(g, full_names, red_names, bpl, length, block_rows),
+        grid=(n_lanes * bpl,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shapes,
+        interpret=interpret,
+    )
+
+    def run(*ins2d: jax.Array) -> Tuple[jax.Array, ...]:
+        outs = call(*ins2d)
+        carries = outs[len(full_names):]
+        return tuple(outs[:len(full_names)]) + tuple(
+            _finish(g.nodes[r], c.reshape(n_lanes, tile))
+            for r, c in zip(red_names, carries))
+
+    return run
 
 
 def fabric_reduce_lanes(g: D.DFG, inputs_list: List[Dict[str, np.ndarray]],
@@ -128,9 +203,6 @@ def fabric_reduce_lanes(g: D.DFG, inputs_list: List[Dict[str, np.ndarray]],
     :func:`run_dfg_lanes`. Results are bit-exact against the functional
     executor per lane (the 5-way conformance contract).
     """
-    if interpret is None:
-        interpret = default_interpret()
-    in_names = list(g.inputs)
     out_names = list(g.outputs)
     n_lanes = len(inputs_list)
     lengths = {int(np.asarray(v).shape[0])
@@ -142,23 +214,12 @@ def fabric_reduce_lanes(g: D.DFG, inputs_list: List[Dict[str, np.ndarray]],
     (length,) = lengths
     check_stream_length(g, length)
 
-    # classify outputs: reduction-fed (one (1,1) carry block per reduction
-    # node) vs full-rate streams (tile blocks)
-    red_of: Dict[str, str] = {}
-    for o in out_names:
-        e = g.operand(o, "a")
-        if g.nodes[e.src].is_reduction():
-            red_of[o] = e.src
-    full_names = [o for o in out_names if o not in red_of]
-    red_names = sorted(set(red_of.values()))
-
     if length == 0:
         return [{o: np.zeros(0, dtype=I32) for o in out_names}
                 for _ in inputs_list]
 
     tile = block_rows * LANES
     padded = pl.cdiv(length, tile) * tile
-    bpl = padded // tile                   # tiles (grid steps) per lane
 
     def stack(name: str) -> jax.Array:
         lanes = []
@@ -167,31 +228,9 @@ def fabric_reduce_lanes(g: D.DFG, inputs_list: List[Dict[str, np.ndarray]],
             lanes.append(jnp.pad(x, (0, padded - length)))
         return jnp.concatenate(lanes).reshape(-1, LANES)
 
-    ins2d = [stack(name) for name in in_names]
-    block = (block_rows, LANES)
-    in_specs = [pl.BlockSpec(block, lambda i: (i, 0)) for _ in in_names]
-    out_specs = [pl.BlockSpec(block, lambda i: (i, 0)) for _ in full_names]
-    out_shapes = [jax.ShapeDtypeStruct((n_lanes * padded // LANES, LANES),
-                                       jnp.int32) for _ in full_names]
-    # one carry/emission block per reduction node, revisited by every grid
-    # step of its lane (sequential TPU grids make the accumulation sound)
-    out_specs += [pl.BlockSpec((1, 1), lambda i: (i // bpl, 0))
-                  for _ in red_names]
-    out_shapes += [jax.ShapeDtypeStruct((n_lanes, 1), jnp.int32)
-                   for _ in red_names]
-
-    fn = pl.pallas_call(
-        _emit_body(g, in_names, full_names, red_names, bpl, length,
-                   block_rows),
-        grid=(n_lanes * bpl,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )
-    outs = fn(*ins2d)
-    if not isinstance(outs, (list, tuple)):
-        outs = [outs]
+    red_of, full_names, red_names = _output_roles(g)
+    run = jax.jit(lanes_call(g, n_lanes, length, block_rows, interpret))
+    outs = run(*[stack(name) for name in g.inputs])
     full_vals = {name: np.asarray(o).reshape(n_lanes, padded)
                  for name, o in zip(full_names, outs)}
     red_vals = {name: np.asarray(o)
@@ -202,7 +241,7 @@ def fabric_reduce_lanes(g: D.DFG, inputs_list: List[Dict[str, np.ndarray]],
         lane: Dict[str, np.ndarray] = {}
         for o in out_names:
             if o in red_of:
-                lane[o] = red_vals[red_of[o]][k].astype(I32)
+                lane[o] = red_vals[red_of[o]][k:k + 1].astype(I32)
             else:
                 v = full_vals[o][k][:length].astype(I32)
                 if g.nodes[o].emit_every == 0 and v.size:

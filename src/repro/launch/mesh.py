@@ -6,20 +6,12 @@ from typing import Sequence, Tuple
 import jax
 
 
-def compat_make_mesh(shape: Sequence[int],
-                     axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across JAX versions.
-
-    ``jax.sharding.AxisType`` only exists in some JAX releases (it was added,
-    renamed, and moved across 0.4.x/0.5.x); where present we request Auto
-    axes explicitly (the pre-AxisType default), otherwise the plain call
-    already means the same thing.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def auto_mesh(shape: Sequence[int],
+              axes: Tuple[str, ...]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis explicitly ``AxisType.Auto``
+    (compiler-chosen sharding propagation, as the launch stack expects)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -28,11 +20,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     whose jax platform exposes enough devices (see launch/dryrun.py)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1) -> jax.sharding.Mesh:
     """Debug mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     data = n // model_axis
-    return compat_make_mesh((data, model_axis), ("data", "model"))
+    return auto_mesh((data, model_axis), ("data", "model"))

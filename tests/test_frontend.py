@@ -117,6 +117,26 @@ def test_elementwise_lowering(fn, n_in):
     _check_traced(fn, n_in)
 
 
+@pytest.mark.parametrize("backend", ["sim", "pallas"])
+def test_jit_call_primitive_inlines(backend):
+    """``jnp.clip`` and ``//`` trace as nested ``jit`` call equations: the
+    tracer inlines them, so a clip kernel compiles and serves bit-exact vs
+    its jnp oracle, and an op inside the call that the fabric lacks is
+    named itself, not as the call."""
+    from repro.engine import Engine
+
+    def clip3(x):
+        return jnp.clip(x, -40, 40) * 3
+
+    eng = Engine(backend=backend)
+    art = eng.compile(clip3, 64)
+    x = rng.integers(-100, 100, 64).astype(np.int32)
+    np.testing.assert_array_equal(eng.run(art, {"x": x})["out0"],
+                                  np.asarray(clip3(jnp.asarray(x))))
+    with pytest.raises(UnsupportedPrimitiveError, match="primitive 'div'"):
+        trace(lambda v: v // 4, 16)
+
+
 def test_dot_product_lowering():
     g = trace(lambda a, b: jnp.dot(a, b), 32, name="dotk")
     assert g.canonical_signature() == K.mac1(32).canonical_signature()

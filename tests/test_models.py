@@ -150,8 +150,8 @@ def test_moe_shard_map_matches_gspmd():
     if len(jax.devices()) < 4:
         import pytest
         pytest.skip("needs 4 local devices (run under dryrun env)")
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((2, 2), ("data", "model"))
     spec = MoESpec(n_experts=4, top_k=2, capacity_factor=8.0)
     p = moe_init(jax.random.PRNGKey(0), 32, 64, spec, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32), jnp.float32)

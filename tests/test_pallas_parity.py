@@ -390,11 +390,59 @@ def test_lane_grid_failure_falls_back_to_per_request(monkeypatch):
     monkeypatch.setattr(eng, "_run_lanes",
                         lambda batch: (_ for _ in ()).throw(
                             RuntimeError("grid failed")))
+    assert eng.last_lane_error is None
     eng.flush()
     assert eng.stats.lane_batches == 0
+    assert eng.stats.lane_batch_failures == 1
+    assert eng.last_lane_error == "RuntimeError: grid failed"
     for i, h in enumerate(hs):
         np.testing.assert_array_equal(h.result()["out"],
                                       np.maximum(np.arange(8) + i, 0))
+
+
+@pytest.mark.parametrize("op,init", [
+    (AluOp.ADD, 5), (AluOp.SUB, 7), (AluOp.MUL, 3), (AluOp.AND, -1),
+    (AluOp.OR, 0), (AluOp.XOR, 9)], ids=lambda v: getattr(v, "name", v))
+def test_carry_fold_bit_exact_per_op(op, init):
+    """Each reduction op folds its masked tiles elementwise into a tile
+    carry and finishes (tile -> scalar, then ``acc_init``) after the
+    kernel: bit-exact, wrapping int32, vs the executor for every lane of
+    a grid whose lanes span a padded second tile."""
+    from repro.kernels.fabric_reduce import run_dfg_lanes
+    n = 1500
+    b = D.DFG.build(f"carry_{op.name.lower()}")
+    m = b.alu("m", AluOp.MUL, b.inp("x"), b.inp("y"))
+    b.out("out0", b.alu("s", op, m, acc_init=init, emit_every=n))
+    g = b.done()
+    # odd operands keep a wrapping product from collapsing to zero
+    lanes = [{k: rng.integers(-5000, 5000, n).astype(np.int32) | 1
+              for k in ("x", "y")} for _ in range(3)]
+    for ins, out in zip(lanes, run_dfg_lanes(g, lanes)):
+        np.testing.assert_array_equal(out["out0"], execute(g, ins)["out0"])
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it and nothing is set.
+    Unset: the fixed in-checkout directory on an accelerator, no cache in
+    interpret mode."""
+    import jax
+    from repro.kernels import fabric_reduce as FR
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert FR.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setattr(FR, "default_interpret", lambda: True)
+    assert FR.use_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.setattr(FR, "default_interpret", lambda: False)
+    try:
+        assert FR.use_compile_cache() == str(FR.COMPILE_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == \
+            str(FR.COMPILE_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert FR.COMPILE_CACHE_DIR.name == ".jax_cache"
 
 
 def test_engine_rejects_unknown_backend():

@@ -11,10 +11,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax import lax
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 from repro.runtime.pipeline import pipeline_forward
 
-mesh = compat_make_mesh((4,), ("pod",))
+mesh = auto_mesh((4,), ("pod",))
 rngk = jax.random.PRNGKey(0)
 L, D, B = 8, 16, 12
 params = {"w": jax.random.normal(rngk, (L, D, D)) * 0.3,

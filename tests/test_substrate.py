@@ -148,8 +148,8 @@ def test_checkpoint_async(tmp_path):
 
 def test_elastic_remesh_roundtrip(tmp_path):
     """Restore a checkpoint onto a different ('smaller cluster') mesh."""
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("data",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1,), ("data",))
     tree = {"w": np.arange(16, dtype=np.float32).reshape(4, 4)}
     out = elastic_remesh(tree, mesh, {"w": P("data", None)})
     np.testing.assert_array_equal(np.asarray(out["w"]), tree["w"])
